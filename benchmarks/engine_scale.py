@@ -503,6 +503,8 @@ def main() -> None:
                     help="shrink every config to a seconds-scale smoke "
                          "and skip BENCH_*.json writes (CI)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    print(f"compile cache: {enable_compile_cache()}", flush=True)
 
     if args.toy:
         global TOY

@@ -53,6 +53,9 @@ _COERCE_BUILTINS = ("float", "int", "bool")
 _COERCE_METHODS = ("item", "tolist", "numpy", "block_until_ready")
 _UPLOAD_FNS = ("asarray", "array", "zeros", "full", "ones", "arange")
 _SAFE_ITER_CALLS = ("range", "enumerate", "zip", "reversed")
+# the float64 scope as called: ``jax.enable_x64(True)`` or, after
+# ``from jax import enable_x64``, the bare name
+_X64_SCOPES = ("enable_x64", "jax.enable_x64")
 
 
 def _dotted(node: ast.AST) -> Optional[str]:
@@ -167,6 +170,8 @@ class _ModuleInfo(ast.NodeVisitor):
     def visit_Call(self, node: ast.Call) -> None:
         # pallas_call(kernel, ...) / pallas_call(partial(kernel, ...), ...)
         fn = _dotted(node.func)
+        if fn in _X64_SCOPES:
+            self.uses_x64 = True
         if fn and fn.split(".")[-1] == "pallas_call" and node.args:
             target = _callable_target(node.args[0])
             if target:
@@ -274,8 +279,7 @@ class _FunctionLint(ast.NodeVisitor):
     def visit_With(self, node: ast.With) -> None:
         is_x64 = any(
             isinstance(item.context_expr, ast.Call)
-            and _dotted(item.context_expr.func) in
-            ("enable_x64", "jax.experimental.enable_x64")
+            and _dotted(item.context_expr.func) in _X64_SCOPES
             for item in node.items)
         self.x64_depth += is_x64
         self.generic_visit(node)

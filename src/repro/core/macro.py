@@ -25,6 +25,7 @@ class MacroAllocator:
     policy_params: Optional[object] = None     # trained PPO params
     predictor: Optional[Callable] = None       # hist -> (R,) distribution
     use_sinkhorn_kernel: bool = False
+    kernel_interpret: bool = False
 
     def __post_init__(self):
         r = self.n_regions
@@ -70,7 +71,8 @@ class MacroAllocator:
         if self.use_sinkhorn_kernel:
             from repro.kernels.sinkhorn.ops import sinkhorn_plan
             plan = sinkhorn_plan(mu[None], nu[None], c[None],
-                                 reg=self.reg)[0]
+                                 reg=self.reg,
+                                 interpret=self.kernel_interpret)[0]
         else:
             plan = sinkhorn(mu, nu, c, reg=self.reg)
         return np.asarray(routing_probs(plan))

@@ -252,7 +252,7 @@ def hw_load_matrix_np(task_feats: np.ndarray,
 
 def hw_load_matrix(task_feats: np.ndarray, server_feats: np.ndarray, *,
                    backend: str = "numpy",
-                   interpret: bool = True) -> np.ndarray:
+                   interpret: bool = False) -> np.ndarray:
     """(N, S) W_HW*hw + W_LOAD*load via the selected backend.
     ``backend="pallas"`` runs it through the ``compat_score`` kernel
     (float32, no locality operand — the Eq-10 term is folded in on the
@@ -269,7 +269,7 @@ def hw_load_matrix(task_feats: np.ndarray, server_feats: np.ndarray, *,
 
 def batched_score_matrix(task_feats: np.ndarray, server_feats: np.ndarray,
                          locality: np.ndarray, *, backend: str = "numpy",
-                         interpret: bool = True) -> np.ndarray:
+                         interpret: bool = False) -> np.ndarray:
     """One (N, S) Eq 7-10 static score matrix: W_HW*hw + W_LOAD*load +
     W_LOC*locality.  Locality is added on the host so the allocator can
     apply within-slot locality updates as column deltas."""
@@ -289,7 +289,7 @@ class MicroAllocator:
     KEEP = 4                      # history depth (legacy tracker default)
 
     def __init__(self, sigma: float = 1.0, headroom: float = 2.0, *,
-                 backend: str = "numpy", interpret: bool = True,
+                 backend: str = "numpy", interpret: bool = False,
                  fused: bool = False):
         if backend not in ("numpy", "pallas", "jax", "fused"):
             raise ValueError(f"unknown micro backend: {backend!r}")

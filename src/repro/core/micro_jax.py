@@ -20,8 +20,9 @@ Pad-and-mask retrace policy: the task axis is padded to a shape bucket
 masked out of eligibility, so each run compiles only a handful of
 distinct ``(N_pad, S)`` scan shapes instead of retracing per slot.  The
 static score base can optionally come from the fused
-``kernels/compat_score`` Pallas kernel (float32; interpreted in CI,
-un-interpreted on real TPUs) via ``fused=True``.
+``kernels/compat_score`` Pallas kernel (float32; compiled for the TPU,
+interpreted only when the allocator is built with ``interpret=True``)
+via ``fused=True``.
 """
 from __future__ import annotations
 
@@ -32,7 +33,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.analysis import sanitize
 from repro.core.micro_state import EMPTY, LocalityState
@@ -223,7 +223,7 @@ def assign_scan(alloc, obs, ridx: int, lstate: LocalityState, *,
         width = ((0, pad),) + ((0, 0),) * (a.ndim - 1)
         return np.pad(a, width, constant_values=fill)
 
-    with enable_x64(True):
+    with jax.enable_x64(True):
         out, l_mids, l_slots, l_emb, l_nrm = _scan_assign(
             jnp.asarray(padf(base)), jnp.asarray(padf(warmterm)),
             jnp.asarray(lstate.mids), jnp.asarray(lstate.slots),
@@ -534,7 +534,7 @@ def assign_scan_all(alloc, obs, ridx_rows: np.ndarray, *, mem_t, work, mids,
         obs_rt.count("micro.sanitize.scan_all")
     else:
         scan_fn = _scan_assign_multi
-    with enable_x64(True):
+    with jax.enable_x64(True):
         out, lm, ls, le, ln = scan_fn(
             jnp.asarray(st.tflops[gmap]), jnp.asarray(st.mem_gb[gmap]),
             jnp.asarray(st.kind_id[gmap].astype(np.int32)),

@@ -35,7 +35,9 @@ class TortaScheduler:
     # Phase-2 scoring backend: route the batched Eq 7-10 score matrix
     # through the compat_score Pallas kernel (mirrors use_sinkhorn_kernel)
     use_compat_kernel: bool = False
-    kernel_interpret: bool = True
+    # run the Pallas kernels above through the interpreter (CPU tests);
+    # off by default, so on a TPU they compile for the chip
+    kernel_interpret: bool = False
     # Phase-2 micro backend: "numpy" (float64 oracle, default), "jax"
     # (jit-compiled per-region lax.scan greedy over LocalityState ring
     # buffers), "fused" (ONE padded multi-region scan per slot with
@@ -60,7 +62,8 @@ class TortaScheduler:
         self.macro = MacroAllocator(self.n_regions, eta=self.eta,
                                     policy_params=self.policy_params,
                                     predictor=self.predictor,
-                                    use_sinkhorn_kernel=self.use_sinkhorn_kernel)
+                                    use_sinkhorn_kernel=self.use_sinkhorn_kernel,
+                                    kernel_interpret=self.kernel_interpret)
         backend = self.micro_backend or (
             "pallas" if self.use_compat_kernel else "numpy")
         self.micro = MicroAllocator(

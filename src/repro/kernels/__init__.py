@@ -5,14 +5,3 @@ Each kernel package contains:
   ops.py    — jit'd public wrapper (shape checks, dtype policy, vmap rules)
   ref.py    — pure-jnp oracle used by the interpret=True correctness sweeps
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# kernels run on the pinned toolchain and on newer jax alike.
-CompilerParams = getattr(_pltpu, "CompilerParams",
-                         getattr(_pltpu, "TPUCompilerParams", None))
-
-
-def compiler_params(**kw):
-    """Version-portable ``compiler_params=`` value for ``pl.pallas_call``."""
-    return CompilerParams(**kw) if CompilerParams is not None else None

@@ -17,7 +17,7 @@ Operands (model ids are float32-encoded ints; exact below 2^24):
   server_models (S, 1+W) [current model, warm cache x W]
   locality      (N, S)  optional precomputed Eq-10 term
 
-Runs interpreted in CI and un-interpreted on real TPUs; the numpy oracle
+Compiles for the TPU (the CPU tests pass ``interpret=True``); the numpy oracle
 is ``core.micro.hw_load_matrix_np`` plus the allocator's warm matrix
 (pinned in ``tests/test_micro_jit.py``), the jnp oracle is
 ``ref.fused_score_ref``.
@@ -29,8 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params as _compiler_params
 from repro.kernels.compat_score.kernel import (W_LOC, _hw_load_tile
                                                as _hw_load)
 
@@ -38,20 +38,20 @@ W_WARM = 2.0          # same-model (no-switch) bonus, mirrors core.micro
 
 
 def _warm(mid_col, sm):
-    """(bn, bs) warm bonus from the (bs, 1+W) model-channel strip."""
-    cur = sm[:, 0][None, :]
-    hit = jnp.zeros(mid_col.shape[:1] + cur.shape[1:], jnp.bool_)
-    for w in range(1, sm.shape[1]):
-        hit = hit | (mid_col == sm[:, w][None, :])
+    """(bn, bs) warm bonus from the transposed (1+W, bs) model strip."""
+    cur = sm[0:1, :]
+    hit = mid_col == sm[1:2, :]
+    for w in range(2, sm.shape[0]):
+        hit = hit | (mid_col == sm[w:w + 1, :])
     return jnp.where(mid_col == cur, 1.0,
                      jnp.where(hit, 0.4, 0.0))
 
 
 def _fused_kernel(t_ref, s_ref, tm_ref, sm_ref, o_ref):
-    tf = t_ref[...].astype(jnp.float32)
-    sf = s_ref[...].astype(jnp.float32)
-    mid = tm_ref[...].astype(jnp.float32)[:, 0][:, None]   # (bn, 1)
-    sm = sm_ref[...].astype(jnp.float32)                   # (bs, 1+W)
+    tf = t_ref[...].astype(jnp.float32)                    # (bn, 8)
+    sf = s_ref[...].astype(jnp.float32)                    # (8, bs)
+    mid = tm_ref[...].astype(jnp.float32)                  # (bn, 1)
+    sm = sm_ref[...].astype(jnp.float32)                   # (1+W, bs)
     score = _hw_load(tf, sf) + W_WARM * _warm(mid, sm)
     o_ref[...] = score.astype(o_ref.dtype)
 
@@ -59,7 +59,7 @@ def _fused_kernel(t_ref, s_ref, tm_ref, sm_ref, o_ref):
 def _fused_kernel_loc(t_ref, s_ref, tm_ref, sm_ref, loc_ref, o_ref):
     tf = t_ref[...].astype(jnp.float32)
     sf = s_ref[...].astype(jnp.float32)
-    mid = tm_ref[...].astype(jnp.float32)[:, 0][:, None]
+    mid = tm_ref[...].astype(jnp.float32)
     sm = sm_ref[...].astype(jnp.float32)
     loc = loc_ref[...].astype(jnp.float32)
     score = (_hw_load(tf, sf) + W_WARM * _warm(mid, sm) + W_LOC * loc)
@@ -94,13 +94,15 @@ def fused_score(task_feats: jax.Array, server_feats: jax.Array,
             locality = jnp.pad(locality,
                                ((0, nn * bn - n), (0, ns * bs - s)))
 
+    # server-side operands go in transposed (feature rows, S lanes); see
+    # the layout note in ``kernel.py``
     in_specs = [
         pl.BlockSpec((bn, 8), lambda i, j: (i, 0)),
-        pl.BlockSpec((bs, 8), lambda i, j: (j, 0)),
+        pl.BlockSpec((8, bs), lambda i, j: (0, j)),
         pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
-        pl.BlockSpec((bs, w1), lambda i, j: (j, 0)),
+        pl.BlockSpec((w1, bs), lambda i, j: (0, j)),
     ]
-    operands = [task_feats, server_feats, tm, sm]
+    operands = [task_feats, server_feats.T, tm, sm.T]
     kernel = _fused_kernel
     if locality is not None:
         in_specs.append(pl.BlockSpec((bn, bs), lambda i, j: (i, j)))
@@ -113,7 +115,7 @@ def fused_score(task_feats: jax.Array, server_feats: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bn, bs), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nn * bn, ns * bs), jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(*operands)

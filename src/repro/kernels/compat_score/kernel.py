@@ -14,6 +14,12 @@ Locality       (N, S): precomputed Eq-10 history term.
 Grid tiles (N, S); each program computes a (bn, bs) tile in VMEM from two
 feature strips — at fleet scale (1e5 tasks x 1e4 servers per §III-A) this is
 the micro layer's dominant cost and is embarrassingly tileable.
+
+TPU layout: the wrapper hands the kernel the server features transposed,
+(8, S), so each server feature is a lane-dense (1, bs) row and each task
+feature a (bn, 1) column; every tile term is then a plain broadcast, with
+no in-kernel transpose.  The one-hot kind match is three broadcast
+products rather than a K=3 matmul (exact for one-hot rows).
 """
 from __future__ import annotations
 
@@ -22,26 +28,25 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import compiler_params as _compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 W_HW, W_LOAD, W_LOC = 0.4, 0.4, 0.2
 
 
 def _hw_load_tile(tf, sf):
-    demand = tf[:, 0][:, None]
-    mem_t = tf[:, 1][:, None]
-    kind_t = tf[:, 2:5]                            # (bn, 3)
-    tflops = sf[:, 0][None, :]
-    mem_s = sf[:, 1][None, :]
-    kind_s = sf[:, 2:5]                            # (bs, 3)
-    util = sf[:, 5][None, :]
-    queue = sf[:, 6][None, :]
-    cap = sf[:, 7][None, :]
+    """(bn, 8) task strip x (8, bs) transposed server strip -> (bn, bs)."""
+    demand = tf[:, 0:1]
+    mem_t = tf[:, 1:2]
+    tflops = sf[0:1, :]
+    mem_s = sf[1:2, :]
+    util = sf[5:6, :]
+    queue = sf[6:7, :]
+    cap = sf[7:8, :]
 
     c = jnp.minimum(1.0, tflops / jnp.maximum(demand, 1e-9))
     m = jnp.minimum(1.0, mem_s / jnp.maximum(mem_t, 1e-9))
-    match = jax.lax.dot(kind_t, kind_s.T)          # 1 if same kind
+    match = (tf[:, 2:3] * sf[2:3, :] + tf[:, 3:4] * sf[3:4, :]
+             + tf[:, 4:5] * sf[4:5, :])                # 1 if same kind
     type_match = 0.5 + 0.5 * match
     hw = c * m * type_match
     load = jnp.exp(-4.0 * (util + queue) / jnp.maximum(cap, 1e-9))
@@ -50,14 +55,14 @@ def _hw_load_tile(tf, sf):
 
 def _kernel(t_ref, s_ref, loc_ref, o_ref):
     tf = t_ref[...].astype(jnp.float32)            # (bn, 8)
-    sf = s_ref[...].astype(jnp.float32)            # (bs, 8)
+    sf = s_ref[...].astype(jnp.float32)            # (8, bs)
     loc = loc_ref[...].astype(jnp.float32)         # (bn, bs)
     o_ref[...] = (_hw_load_tile(tf, sf) + W_LOC * loc).astype(o_ref.dtype)
 
 
 def _kernel_noloc(t_ref, s_ref, o_ref):
     tf = t_ref[...].astype(jnp.float32)            # (bn, 8)
-    sf = s_ref[...].astype(jnp.float32)            # (bs, 8)
+    sf = s_ref[...].astype(jnp.float32)            # (8, bs)
     o_ref[...] = _hw_load_tile(tf, sf).astype(o_ref.dtype)
 
 
@@ -85,9 +90,9 @@ def compat_score(task_feats: jax.Array, server_feats: jax.Array,
 
     in_specs = [
         pl.BlockSpec((bn, 8), lambda i, j: (i, 0)),
-        pl.BlockSpec((bs, 8), lambda i, j: (j, 0)),
+        pl.BlockSpec((8, bs), lambda i, j: (0, j)),
     ]
-    operands = [task_feats, server_feats]
+    operands = [task_feats, server_feats.T]
     kernel = _kernel_noloc
     if locality is not None:
         in_specs.append(pl.BlockSpec((bn, bs), lambda i, j: (i, j)))
@@ -100,7 +105,7 @@ def compat_score(task_feats: jax.Array, server_feats: jax.Array,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bn, bs), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nn * bn, ns * bs), jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(*operands)

@@ -22,7 +22,7 @@ from repro.kernels.compat_score.ref import compat_score_ref
 
 
 def score_matrix(task_feats, server_feats, locality=None, *,
-                 use_pallas=True, interpret=True) -> jax.Array:
+                 use_pallas=True, interpret=False) -> jax.Array:
     """hw+load(+locality) scores.  ``locality=None`` skips the locality
     operand (callers that fold Eq-10 in on the host pass nothing instead
     of allocating an (N, S) zeros matrix per call)."""

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params as _compiler_params
-
 NEG_INF = -1e30
 
 
@@ -89,7 +87,7 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, hd), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k_cache, v_cache, valid2)

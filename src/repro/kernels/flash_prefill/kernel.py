@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params as _compiler_params
-
 NEG_INF = -1e30
 
 
@@ -116,7 +114,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((g, bq), jnp.float32),
             pltpu.VMEM((g, bq, hd), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params as _compiler_params
-
 
 def _kernel(dt_ref, bm_ref, cm_ref, x_ref, a_ref, d_ref, y_ref, h_sc, *,
             chunk: int, n_chunks: int):
@@ -83,7 +81,7 @@ def selective_scan(dt: jax.Array, bm: jax.Array, cm: jax.Array, x: jax.Array,
         out_specs=pl.BlockSpec((1, ch, db), lambda bi, di, ci: (bi, ci, di)),
         out_shape=jax.ShapeDtypeStruct((b, n_chunks * ch, d_in), x.dtype),
         scratch_shapes=[pltpu.VMEM((db, n), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(dt, bm, cm, x, a, d2)

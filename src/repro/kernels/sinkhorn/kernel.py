@@ -13,8 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels import compiler_params as _compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(mu_ref, nu_ref, c_ref, p_ref, *, n_iters: int, reg: float):
@@ -71,7 +70,7 @@ def sinkhorn_batched(mu: jax.Array, nu: jax.Array, cost: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((bb, r, r), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb * bb, r, r), jnp.float32),
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(mu, nu, cost)

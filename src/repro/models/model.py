@@ -16,6 +16,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ArchConfig
@@ -141,7 +142,6 @@ class Model:
         shard — XLA gathers the (far smaller) projection weights, and only
         the GQA-small K/V are all-gathered across sequence shards."""
         cfg, rules = self.cfg, self.rules
-        from repro.models._compat import shard_map
         from repro.models.layers import apply_rope, gqa_attention
         mesh = rules.mesh
         seq = rules.seq_axis

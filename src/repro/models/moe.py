@@ -24,9 +24,10 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
+
 from repro.configs import ArchConfig, MoEConfig
-from repro.models._compat import shard_map
 from repro.models.params import ParamDesc
 from repro.sharding.specs import AxisRules, batch_axes
 

@@ -30,7 +30,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.analysis import sanitize
 from repro.obs import runtime as obs_rt
@@ -254,7 +253,7 @@ class JaxStepper:
 
     def _make_step(self) -> EngineStep:
         if self._static is None:
-            with enable_x64(True):
+            with jax.enable_x64(True):
                 self._static = static_arrays(self.state)
         return EngineStep.from_state(self.state, self._static)
 
@@ -266,7 +265,7 @@ class JaxStepper:
                                str(st.n_servers))
         obs_rt.count("engine.host_sync.warm_step")
         warm_fn, _, _ = self._kernels()
-        with enable_x64(True):
+        with jax.enable_x64(True):
             step = warm_fn(self._make_step(),
                            jnp.asarray(np.float64(slot_s)))
             step.write_back(st, fields=("state", "warm_remaining_s"))
@@ -290,7 +289,7 @@ class JaxStepper:
         work_p = np.pad(work_raw.astype(np.float64), (0, pad))
         valid = np.pad(np.ones(k, bool), (0, pad))
         _, apply_fn, _ = self._kernels()
-        with enable_x64(True):
+        with jax.enable_x64(True):
             step, sw, energy, wait, wk = apply_fn(
                 self._make_step(), jnp.asarray(gs_p),
                 jnp.asarray(mids_p), jnp.asarray(work_p),
@@ -308,7 +307,7 @@ class JaxStepper:
                                str(st.n_servers))
         obs_rt.count("engine.host_sync.close_step")
         _, _, close_fn = self._kernels()
-        with enable_x64(True):
+        with jax.enable_x64(True):
             step, power_j, act = close_fn(
                 self._make_step(), jnp.asarray(np.float64(slot_s)))
             step.write_back(st, fields=("queue_s", "util", "idle_slots"))

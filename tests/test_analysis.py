@@ -34,7 +34,7 @@ HEADER = """\
     import jax.numpy as jnp
     import numpy as np
     from functools import partial
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 """
 
 
@@ -105,6 +105,22 @@ def test_lint_upload_outside_x64():
     """)
     assert [f.rule for f in out] == ["jnp-upload-outside-x64"] * 2
     assert {f.line for f in out} == {8, 9}
+
+
+def test_lint_upload_outside_x64_attribute_scope():
+    # a module that only ever calls ``jax.enable_x64(...)`` (no import of
+    # the bare name) still owns float64 math and still gets the rule
+    out = _lint("""\
+    import jax
+    import jax.numpy as jnp
+
+    def host_wrapper(x):
+        a = jnp.asarray(x)                  # hazard: ambient dtype
+        with jax.enable_x64(True):
+            b = jnp.asarray(x)              # fine: lexical x64 scope
+        return a, b
+    """)
+    assert [(f.rule, f.line) for f in out] == [("jnp-upload-outside-x64", 5)]
 
 
 def test_lint_retrace_rules():

@@ -149,7 +149,8 @@ def test_micro_backends_agree_end_to_end(parity_world):
                   seed=0).run(6).summary()
     s_pl = Engine(topo, copy.deepcopy(cluster), wl,
                   TortaScheduler(topo.n_regions, seed=0,
-                                 use_compat_kernel=True),
+                                 use_compat_kernel=True,
+                                 kernel_interpret=True),
                   seed=0).run(6).summary()
     assert s_pl["completed"] == pytest.approx(s_np["completed"], rel=0.02)
     assert s_pl["mean_response_s"] == pytest.approx(

@@ -187,7 +187,8 @@ def test_scan_fused_kernel_end_to_end():
     s_fu = Engine(topo, copy.deepcopy(cluster), wl,
                   TortaScheduler(topo.n_regions, seed=0,
                                  micro_backend="jax",
-                                 micro_fused_kernel=True),
+                                 micro_fused_kernel=True,
+                                 kernel_interpret=True),
                   seed=0).run(5).summary()
     assert s_fu["completed"] == pytest.approx(s_jx["completed"], rel=0.02)
     assert s_fu["mean_response_s"] == pytest.approx(
