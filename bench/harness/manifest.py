@@ -1,0 +1,69 @@
+"""``BENCHMARK.json`` and the files it names, found by name.
+
+A cell names a configuration and a traffic mix; each lives in a file of
+its own (``bench/configs/<name>.json``, ``bench/traffic/<name>.json``), and
+each per-layer metric is a reader in ``bench/metrics/<name>.py`` with a
+``read(ctx)`` function.  Adding a cell, a mix or a metric is adding such
+files and entries; nothing here changes.
+"""
+from __future__ import annotations
+
+import importlib.util
+import json
+import pathlib
+from typing import Callable, List, Optional
+
+BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
+
+
+class Manifest:
+    def __init__(self, root: pathlib.Path):
+        self.root = pathlib.Path(root)
+        self.bench = self.root / BENCH_DIR.name
+        with open(self.root / "BENCHMARK.json") as fh:
+            self.data = json.load(fh)
+
+    def cell(self, name: str) -> dict:
+        for w in self.data["workloads"]:
+            if w["name"] == name:
+                return w
+        raise KeyError(f"no workload {name!r} in BENCHMARK.json; cells: "
+                       f"{', '.join(w['name'] for w in self.data['workloads'])}")
+
+    def config(self, name: str) -> dict:
+        for c in self.data["configs"]:
+            if c["name"] == name:
+                with open(self.root / c["file"]) as fh:
+                    return json.load(fh)
+        raise KeyError(f"no config {name!r} in BENCHMARK.json")
+
+    def traffic(self, name: str) -> dict:
+        with open(self.bench / "traffic" / f"{name}.json") as fh:
+            return json.load(fh)
+
+    def metrics_for(self, kind: str, cell: str) -> List[dict]:
+        """The ``end_to_end`` or ``per_layer`` metrics a cell reports."""
+        return [m for m in self.data[kind]
+                if cell in m.get("workloads", [cell])]
+
+    def reader(self, metric: str) -> Callable:
+        path = self.bench / "metrics" / f"{metric}.py"
+        spec = importlib.util.spec_from_file_location(
+            f"bench_metric_{metric.replace('.', '_').replace('-', '_')}",
+            path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        return module.read
+
+
+def span_total(ctx, name: str) -> Optional[float]:
+    """Seconds spent in span ``name`` inside the traced window (None when
+    the span never opened there)."""
+    rows = [d for n, _, d in ctx.spans if n == name]
+    return sum(rows) if rows else None
+
+
+def per_slot_ms(ctx, seconds: Optional[float]) -> Optional[float]:
+    if seconds is None or ctx.slots <= 0:
+        return None
+    return 1000.0 * seconds / ctx.slots

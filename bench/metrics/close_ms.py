@@ -1,0 +1,7 @@
+"""Slot close (``sim/engine.py``, ``sim/engine_jax.py``): span
+``engine.slot_close`` per slot of the traced window."""
+from harness.manifest import per_slot_ms, span_total
+
+
+def read(ctx):
+    return per_slot_ms(ctx, span_total(ctx, "engine.slot_close"))
