@@ -32,10 +32,15 @@ JIT_EXTENT_GLOBS = (
 # Keyed by module basename-relative path suffix; values are function
 # names.  Nested ``def``s inside traced functions are traced implicitly;
 # this table covers module-level helpers.
+# The ``*_impl`` bodies are traced through the named entry functions
+# the production jits wrap (``micro_scan_all``, ``engine_*``) and
+# through the sanitizer's checkified partials.
 EXTRA_TRACED: Dict[str, Tuple[str, ...]] = {
     "src/repro/core/micro_jax.py": (
-        "_entry_contrib_tail", "_entry_contribs", "_sum_newest_first"),
-    "src/repro/sim/engine_jax.py": (),
+        "_entry_contrib_tail", "_entry_contribs", "_sum_newest_first",
+        "_scan_assign_multi_impl"),
+    "src/repro/sim/engine_jax.py": (
+        "warm_step_impl", "apply_single_impl", "close_step_impl"),
 }
 
 # Host-side wrapper functions inside jit-extent modules: they build

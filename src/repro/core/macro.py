@@ -13,6 +13,7 @@ from repro.core.env import K_HIST
 from repro.core.ot import (cost_matrix, normalize_masses, routing_probs,
                            sinkhorn)
 from repro.core.predictor import EmaPredictor
+from repro.obs import runtime as obs_rt
 
 
 @dataclasses.dataclass
@@ -62,20 +63,24 @@ class MacroAllocator:
 
     def ot_plan(self, demand: np.ndarray, capacity: np.ndarray,
                 power_cost: np.ndarray, latency: np.ndarray) -> np.ndarray:
-        mu, nu = normalize_masses(jnp.asarray(demand, jnp.float32),
-                                  jnp.asarray(capacity, jnp.float32))
-        c = cost_matrix(jnp.asarray(power_cost / max(power_cost.max(), 1e-9),
-                                    jnp.float32),
-                        jnp.asarray(latency / max(latency.max(), 1e-9),
-                                    jnp.float32))
-        if self.use_sinkhorn_kernel:
-            from repro.kernels.sinkhorn.ops import sinkhorn_plan
-            plan = sinkhorn_plan(mu[None], nu[None], c[None],
-                                 reg=self.reg,
-                                 interpret=self.kernel_interpret)[0]
-        else:
-            plan = sinkhorn(mu, nu, c, reg=self.reg)
-        return np.asarray(routing_probs(plan))
+        with obs_rt.span("macro.ot"):
+            dem, cap, power, lat = (jnp.asarray(x, jnp.float32) for x in (
+                demand, capacity, power_cost / max(power_cost.max(), 1e-9),
+                latency / max(latency.max(), 1e-9)))
+            obs_rt.count_transfer("h2d", "macro",
+                                  lambda: (dem, cap, power, lat))
+            mu, nu = normalize_masses(dem, cap)
+            c = cost_matrix(power, lat)
+            if self.use_sinkhorn_kernel:
+                from repro.kernels.sinkhorn.ops import sinkhorn_plan
+                plan = sinkhorn_plan(mu[None], nu[None], c[None],
+                                     reg=self.reg,
+                                     interpret=self.kernel_interpret)[0]
+            else:
+                plan = sinkhorn(mu, nu, c, reg=self.reg)
+            probs = np.asarray(routing_probs(plan))
+            obs_rt.count_transfer("d2h", "macro", lambda: (probs,))
+        return probs
 
     def allocate(self, *, demand: np.ndarray, predicted: np.ndarray,
                  capacity: np.ndarray, power_cost: np.ndarray,

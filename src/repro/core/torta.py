@@ -97,15 +97,16 @@ class TortaScheduler:
         corrupt it if requested, log it, and solve for A_t."""
         with obs_rt.span("macro.phase1"):
             r = self.n_regions
-            q_norm = obs.queue_tasks / max(float(obs.queue_tasks.max()),
-                                           1.0)
-            predicted = self.macro.predict_next(demand, obs.utilization,
-                                                q_norm)
-            if self.prediction_noise > 0:
-                noise = self.rng.dirichlet(np.ones(r))
-                predicted = (1 - self.prediction_noise) * predicted \
-                    + self.prediction_noise * noise
-            self.prediction_log.append(np.asarray(predicted))
+            with obs_rt.span("macro.predict"):
+                q_norm = obs.queue_tasks / max(float(obs.queue_tasks.max()),
+                                               1.0)
+                predicted = self.macro.predict_next(demand, obs.utilization,
+                                                    q_norm)
+                if self.prediction_noise > 0:
+                    noise = self.rng.dirichlet(np.ones(r))
+                    predicted = (1 - self.prediction_noise) * predicted \
+                        + self.prediction_noise * noise
+                self.prediction_log.append(np.asarray(predicted))
 
             # supply = capacity net of existing backlog (temporal load
             # awareness)
@@ -137,19 +138,21 @@ class TortaScheduler:
         a = self._macro_step(obs, demand)
         predicted = self._predicted
 
-        region_of = np.full(n, -1, np.int32)
-        mask = obs.capacities > 0
-        for origin in np.unique(batch.origin):
-            idx = np.flatnonzero(batch.origin == origin)
-            pm = self._row_probs(a, int(origin), mask)
-            region_of[idx] = self.rng.choice(r, size=idx.size, p=pm)
+        with obs_rt.span("macro.sample"):
+            region_of = np.full(n, -1, np.int32)
+            mask = obs.capacities > 0
+            for origin in np.unique(batch.origin):
+                idx = np.flatnonzero(batch.origin == origin)
+                pm = self._row_probs(a, int(origin), mask)
+                region_of[idx] = self.rng.choice(r, size=idx.size, p=pm)
 
         pred_inbound = self._pred_inbound(obs, a, demand, predicted)
         if self.micro.backend == "fused":
             # fused slot path: phase-1 outputs (sampled regions + Eq-6
             # targets from pred_inbound) feed ONE multi-region scan
             # dispatch instead of R per-region assign calls
-            activation = self.micro.activation_targets(obs, pred_inbound)
+            with obs_rt.span("micro.activation"):
+                activation = self.micro.activation_targets(obs, pred_inbound)
             server_of = self.micro.assign_batch_all(obs, batch, region_of)
         else:
             activation = np.empty(r, np.int64)   # api array form

@@ -14,14 +14,38 @@ alongside the device kernels — the host spans remain the source of truth
 for the per-run summary table.
 
 Span taxonomy used by the engine/scheduler wiring (see
-ARCHITECTURE.md §Observability):
+ARCHITECTURE.md §Observability).  The top-level spans tile one engine
+slot, so idle device time always falls under some phase:
 
-* ``schedule.batch``  — the whole scheduler call for the slot
-* ``macro.phase1``    — TORTA phase 1 (predictor + Sinkhorn + A_t)
-* ``micro.assign``    — phase-2 greedy matching (any backend)
-* ``micro.host_sync`` — the one device->host materialization per slot
-* ``engine.apply``    — decision application (grouped/sequential)
-* ``engine.slot_close`` — drain, billing, per-slot metrics
+* ``engine.intake``     — failures, warming, the source call, arrivals,
+  the buffer concat and the ``SlotObs``
+* ``schedule.batch``    — the whole scheduler call for the slot
+
+  * ``macro.phase1``    — TORTA phase 1: ``macro.predict`` (the demand
+    forecast) and ``macro.ot`` (operands, Sinkhorn, routing
+    probabilities and their sync)
+  * ``macro.sample``    — the per-origin region draw
+  * ``micro.activation`` — Eq 6 activation targets
+  * ``micro.assign``    — phase-2 greedy matching (any backend); on the
+    fused path ``micro.pack`` (sort and scatter into numpy operands),
+    ``micro.upload`` (uploads and the scan dispatch) and
+    ``micro.host_sync`` (the one device->host sync per slot)
+
+* ``engine.activate``   — decision validation and server activation
+* ``engine.apply``      — decision application: ``engine.apply.single``
+  (one task per server, jitted on the jax step backend),
+  ``engine.apply.conflict`` (the per-row walk over same-server rows),
+  ``engine.apply.replay`` (the per-task replay when a target went
+  inactive)
+* ``engine.buffer``     — drops and the cross-slot buffer
+* ``engine.slot_close`` — drain, billing, per-slot metrics;
+  ``engine.close_step`` is the jitted close (upload, dispatch, sync,
+  write-back)
+* ``engine.observe``    — the per-slot series recorder
+
+Each record carries the engine slot it opened in (``runtime.begin_slot``)
+and :meth:`Tracer.summary` reports each name's self time beside its
+total.
 """
 from __future__ import annotations
 
@@ -38,6 +62,7 @@ class SpanRecord:
     parent: int          # index of the enclosing span record, -1 if none
     t_start: float       # perf_counter seconds (monotonic)
     duration_s: float = 0.0
+    slot: int = -1       # engine slot the span opened in (-1 outside one)
 
 
 class _Span:
@@ -91,6 +116,7 @@ class Tracer:
         self.clock = clock
         self.records: List[SpanRecord] = []
         self._stack: List[int] = []
+        self.slot = -1           # set once per engine slot
 
     # ------------------------------------------------------------- spans
 
@@ -118,7 +144,7 @@ class Tracer:
         parent = self._stack[-1] if self._stack else -1
         self.records.append(SpanRecord(
             name=name, depth=len(self._stack), parent=parent,
-            t_start=self.clock()))
+            t_start=self.clock(), slot=self.slot))
         self._stack.append(idx)
         return idx
 
@@ -133,19 +159,26 @@ class Tracer:
 
     def summary(self) -> List[Dict]:
         """Per-name aggregate rows, ordered by total time descending:
-        ``{name, count, total_s, mean_s, max_s, depth}`` (depth = the
+        ``{name, count, total_s, self_s, mean_s, max_s, depth}`` (self =
+        total less the time of the spans directly inside; depth = the
         minimum nesting depth the name was seen at)."""
-        agg: Dict[str, Dict] = {}
+        child_s = [0.0] * len(self.records)
         for rec in self.records:
+            if rec.parent >= 0:
+                child_s[rec.parent] += rec.duration_s
+        agg: Dict[str, Dict] = {}
+        for rec, inner in zip(self.records, child_s):
             row = agg.get(rec.name)
             if row is None:
                 agg[rec.name] = {"name": rec.name, "count": 1,
                                  "total_s": rec.duration_s,
+                                 "self_s": rec.duration_s - inner,
                                  "max_s": rec.duration_s,
                                  "depth": rec.depth}
             else:
                 row["count"] += 1
                 row["total_s"] += rec.duration_s
+                row["self_s"] += rec.duration_s - inner
                 row["max_s"] = max(row["max_s"], rec.duration_s)
                 row["depth"] = min(row["depth"], rec.depth)
         rows = sorted(agg.values(), key=lambda r: -r["total_s"])
@@ -158,12 +191,12 @@ class Tracer:
         rows = self.summary()
         if not rows:
             return "(no spans recorded)"
-        lines = [f"{'span':<24} {'count':>7} {'total_s':>9} "
-                 f"{'mean_ms':>9} {'max_ms':>9}"]
+        lines = [f"{'span':<26} {'count':>7} {'total_s':>9} "
+                 f"{'self_s':>9} {'mean_ms':>9} {'max_ms':>9}"]
         for r in rows:
             indent = "  " * r["depth"]
             lines.append(
-                f"{indent + r['name']:<24} {r['count']:>7} "
-                f"{r['total_s']:>9.3f} {r['mean_s'] * 1e3:>9.2f} "
-                f"{r['max_s'] * 1e3:>9.2f}")
+                f"{indent + r['name']:<26} {r['count']:>7} "
+                f"{r['total_s']:>9.3f} {r['self_s']:>9.3f} "
+                f"{r['mean_s'] * 1e3:>9.2f} {r['max_s'] * 1e3:>9.2f}")
         return "\n".join(lines)

@@ -326,7 +326,12 @@ class Engine:
         # decision and apply): replay the legacy per-task loop so the
         # least-backlogged fallback sees queues exactly as they evolve
         obs_rt.count("engine.fallback.inactive_target_slot")
-        return self._apply_sequential(t, batch, decision, alloc, assigned)
+        with obs_rt.span("engine.apply.replay"):
+            out = self._apply_sequential(t, batch, decision, alloc,
+                                         assigned)
+        obs_rt.count("engine.fallback.inactive_target_rows",
+                     int(np.count_nonzero(assigned)))
+        return out
 
     def _apply_grouped(self, t: int, batch, region: np.ndarray,
                        g0: np.ndarray, rows_mask: np.ndarray,
@@ -348,49 +353,50 @@ class Engine:
         energy_total = 0.0
         switch_total = 0.0
         n_switches = 0
+        if pos_single.size:
+            with obs_rt.span("engine.apply.single"):
+                # servers receiving exactly one task: one vectorized pass
+                single_rows = rows[pos_single]
+                gs = g[pos_single]
+                mids = batch.model_idx[single_rows].astype(np.int64)
+                if self._stepper is not None:
+                    # jitted grouped apply (bitwise-equal per-row channels)
+                    sw, energy, wt, wk = self._stepper.apply_single_rows(
+                        gs, mids, batch.work_s[single_rows])
+                    wait[pos_single] = wt
+                else:
+                    speed = np.maximum(st.tflops[gs] / 112.0, 0.1)
+                    sw = st.switch_cost_rows(gs, mids)
+                    energy = np.where(
+                        sw > 0, sw * st.power_w[gs] * SWITCH_POWER_FRAC,
+                        0.0)
+                    st.note_model_rows(gs, mids)
+                    wk = batch.work_s[single_rows] / speed
+                    wait[pos_single] = st.queue_s[gs] + sw
+                    st.queue_s[gs] += sw + wk
+                work[pos_single] = wk
+                net[pos_single] = self.topo.latency[
+                    batch.origin[single_rows], region[single_rows]] / 1000.0
+                energy_total += float(energy.sum())
+                switch_total += float(sw.sum())
+                n_switches += int(np.count_nonzero(sw > 0))
+
         if pos_multi.size:
             # rows applied through the sequential per-task walk even on
             # the jax step backend — the fused path's residual numpy work
             obs_rt.count("engine.fallback.same_server_conflict",
                          pos_multi.size)
-
-        if pos_single.size:
-            # servers receiving exactly one task: one vectorized pass
-            single_rows = rows[pos_single]
-            gs = g[pos_single]
-            mids = batch.model_idx[single_rows].astype(np.int64)
-            if self._stepper is not None:
-                # jitted grouped apply (bitwise-equal per-row channels)
-                sw, energy, wt, wk = self._stepper.apply_single_rows(
-                    gs, mids, batch.work_s[single_rows])
-                wait[pos_single] = wt
-            else:
-                speed = np.maximum(st.tflops[gs] / 112.0, 0.1)
-                sw = st.switch_cost_rows(gs, mids)
-                energy = np.where(sw > 0,
-                                  sw * st.power_w[gs] * SWITCH_POWER_FRAC,
-                                  0.0)
-                st.note_model_rows(gs, mids)
-                wk = batch.work_s[single_rows] / speed
-                wait[pos_single] = st.queue_s[gs] + sw
-                st.queue_s[gs] += sw + wk
-            work[pos_single] = wk
-            net[pos_single] = self.topo.latency[
-                batch.origin[single_rows], region[single_rows]] / 1000.0
-            energy_total += float(energy.sum())
-            switch_total += float(sw.sum())
-            n_switches += int(np.count_nonzero(sw > 0))
-
-        for p in pos_multi:
-            i = int(rows[p])
-            e, s_s, sw_flag, wt, wk, nt = self._apply_one(
-                int(g0[i]), int(batch.model_idx[i]),
-                float(batch.work_s[i]), int(batch.origin[i]),
-                int(region[i]))
-            energy_total += e
-            switch_total += s_s
-            n_switches += sw_flag
-            wait[p], work[p], net[p] = wt, wk, nt
+            with obs_rt.span("engine.apply.conflict"):
+                for p in pos_multi:
+                    i = int(rows[p])
+                    e, s_s, sw_flag, wt, wk, nt = self._apply_one(
+                        int(g0[i]), int(batch.model_idx[i]),
+                        float(batch.work_s[i]), int(batch.origin[i]),
+                        int(region[i]))
+                    energy_total += e
+                    switch_total += s_s
+                    n_switches += sw_flag
+                    wait[p], work[p], net[p] = wt, wk, nt
 
         self.metrics.record_completions(t, wait, work, net)
         np.add.at(alloc, (batch.origin[rows], region[rows]), 1.0)
@@ -453,7 +459,8 @@ class Engine:
         # drain queues + power accounting (whole-array; jitted when the
         # jax step backend is selected — identical elementwise values)
         if self._stepper is not None:
-            power_server, act = self._stepper.close_slot(self.slot_s)
+            with obs_rt.span("engine.close_step"):
+                power_server, act = self._stepper.close_slot(self.slot_s)
         else:
             act = st.active_mask()
             busy = np.minimum(st.queue_s, self.slot_s)
@@ -522,61 +529,73 @@ class Engine:
         src = self.source
         track = self.obs is not None and self.obs.series is not None
         for t in range(t_total):
-            self._step_failures(t)
-            self._progress_warming()
+            # the top-level spans below tile the slot: every phase of it
+            # is inside one of them
+            obs_rt.begin_slot(t)
+            with obs_rt.span("engine.intake"):
+                self._step_failures(t)
+                self._progress_warming()
 
-            new = (src.slot_batch(t) if t < src.n_slots
-                   else TaskBatch.empty())
-            self._record_arrivals(
-                new.origin_counts(r).astype(np.float64))
-            if len(new):
-                obs_rt.count("engine.tasks.arrived", len(new))
-            # buffered tasks get first chance
-            batch = TaskBatch.concat(self.pending_batch, new)
-            self.pending_batch = TaskBatch.empty()
+                new = (src.slot_batch(t) if t < src.n_slots
+                       else TaskBatch.empty())
+                self._record_arrivals(
+                    new.origin_counts(r).astype(np.float64))
+                if len(new):
+                    obs_rt.count("engine.tasks.arrived", len(new))
+                # buffered tasks get first chance
+                batch = TaskBatch.concat(self.pending_batch, new)
+                self.pending_batch = TaskBatch.empty()
 
-            obs = self._obs(t)
-            n_resp0 = len(self.metrics.response_times)
+                obs = self._obs(t)
+                n_resp0 = len(self.metrics.response_times)
             with obs_rt.span("schedule.batch"):
                 decision = self.scheduler.schedule_batch(obs, batch)
-            decision.validate(len(batch), st)
-            overhead_s = 0.0
-            targets = decision.activation_targets(r)
-            if targets:
-                overhead_s += self._apply_activation(targets)
+            with obs_rt.span("engine.activate"):
+                decision.validate(len(batch), st)
+                overhead_s = 0.0
+                targets = decision.activation_targets(r)
+                if targets:
+                    overhead_s += self._apply_activation(targets)
 
             with obs_rt.span("engine.apply"):
                 (alloc, switch_energy_j, switch_s, n_switches,
                  assigned) = self._apply_decision(t, batch, decision)
             overhead_s += switch_s
 
-            # every unassigned row ages out the same way, whether the
-            # scheduler buffered it or its server failed resolution —
-            # resolve-failed tasks used to be exempt, recirculating
-            # forever (and never counting as drops) through long outages
-            n_drop = 0
-            left = np.flatnonzero(~assigned)
-            if left.size:
-                too_old = (t - batch.arrival_slot[left]) >= self.drop_after
-                n_drop = int(np.count_nonzero(too_old))
-                if n_drop:
-                    self.metrics.record_drops(n_drop, t)
-                    obs_rt.count("engine.tasks.dropped", n_drop)
-                keep = left[~too_old]
-                if keep.size:
-                    obs_rt.count("engine.tasks.buffered", keep.size)
-                # reference-faithful buffer order: group rows by origin
-                keep = keep[np.argsort(batch.origin[keep], kind="stable")]
-                self.pending_batch = batch.select(keep)
-            n_assigned = int(np.count_nonzero(assigned))
-            if n_assigned:
-                obs_rt.count("engine.tasks.assigned", n_assigned)
+            with obs_rt.span("engine.buffer"):
+                n_drop = self._buffer_unassigned(t, batch, assigned)
 
             with obs_rt.span("engine.slot_close"):
                 self._finish_slot(t, obs, alloc, switch_energy_j,
                                   n_switches, overhead_s)
             if track:
-                self._observe_slot(t, obs, n_resp0, n_drop)
+                with obs_rt.span("engine.observe"):
+                    self._observe_slot(t, obs, n_resp0, n_drop)
+
+    def _buffer_unassigned(self, t: int, batch, assigned: np.ndarray) -> int:
+        """Age out or re-buffer the slot's unassigned rows; returns the
+        rows dropped.  Every unassigned row ages out the same way, whether
+        the scheduler buffered it or its server failed resolution —
+        resolve-failed tasks used to be exempt, recirculating forever (and
+        never counting as drops) through long outages."""
+        n_drop = 0
+        left = np.flatnonzero(~assigned)
+        if left.size:
+            too_old = (t - batch.arrival_slot[left]) >= self.drop_after
+            n_drop = int(np.count_nonzero(too_old))
+            if n_drop:
+                self.metrics.record_drops(n_drop, t)
+                obs_rt.count("engine.tasks.dropped", n_drop)
+            keep = left[~too_old]
+            if keep.size:
+                obs_rt.count("engine.tasks.buffered", keep.size)
+            # reference-faithful buffer order: group rows by origin
+            keep = keep[np.argsort(batch.origin[keep], kind="stable")]
+            self.pending_batch = batch.select(keep)
+        n_assigned = int(np.count_nonzero(assigned))
+        if n_assigned:
+            obs_rt.count("engine.tasks.assigned", n_assigned)
+        return n_drop
 
     def _observe_slot(self, t: int, obs: SlotObs, n_resp0: int,
                       n_drop: int) -> None:

@@ -12,6 +12,13 @@ jitted kernels cover the interpreted engine surface:
 * :func:`close_step` — queue drain, utilization/idle bookkeeping and the
   per-server power draw of ``Engine._finish_slot``.
 
+Each jits a named function, so its XLA module reads
+``jit_engine_warm_step``, ``jit_engine_apply_single`` or
+``jit_engine_close_step`` in a profile.  ``JaxStepper`` counts the arrays
+each dispatch moves and the bytes it uploads
+(``device.transfers{dir=...,layer=engine}``,
+``device.h2d_bytes{layer=engine}``).
+
 Every op mirrors the numpy engine's float64 expression order bitwise
 (elementwise IEEE ops only — reductions such as the per-region power sum
 and the metrics totals stay on the host over the returned arrays, so the
@@ -41,6 +48,11 @@ from repro.sim.state import (ACTIVE, NO_MODEL, WARM_SLOTS, WARMING,
 def _model_switch_s() -> float:
     from repro.sim.cluster import MODEL_SWITCH_S
     return MODEL_SWITCH_S
+
+
+# the columns ``EngineStep.from_state`` uploads on every dispatch
+DYNAMIC_FIELDS = ("state", "warm_remaining_s", "queue_s", "util",
+                  "idle_slots", "current_model", "warm_models")
 
 
 def static_arrays(st: ClusterState):
@@ -87,19 +99,12 @@ class EngineStep:
             statics = static_arrays(st)
         speed, power_w, switch_scale = statics
         return cls(
-            state=jnp.asarray(st.state),
-            warm_remaining_s=jnp.asarray(st.warm_remaining_s),
-            queue_s=jnp.asarray(st.queue_s),
-            util=jnp.asarray(st.util),
-            idle_slots=jnp.asarray(st.idle_slots),
-            current_model=jnp.asarray(st.current_model),
-            warm_models=jnp.asarray(st.warm_models),
+            **{name: jnp.asarray(getattr(st, name))
+               for name in DYNAMIC_FIELDS},
             speed=speed, power_w=power_w, switch_scale=switch_scale)
 
     def write_back(self, st: ClusterState,
-                   fields=("state", "warm_remaining_s", "queue_s", "util",
-                           "idle_slots", "current_model",
-                           "warm_models")) -> None:
+                   fields=DYNAMIC_FIELDS) -> None:
         """Sync dynamic columns into the numpy ``ClusterState`` (the host
         mirror the schedulers/oracle fallback read); callers narrow
         ``fields`` to the columns their kernel actually wrote."""
@@ -205,9 +210,22 @@ def close_step_impl(step: EngineStep, slot_s, *, checks: bool = False):
 
 
 # Production entries: checks=False compiles to the historical jaxprs.
-warm_step = jax.jit(partial(warm_step_impl, checks=False))
-apply_single = jax.jit(partial(apply_single_impl, checks=False))
-close_step = jax.jit(partial(close_step_impl, checks=False))
+# Named functions, not partials, so the XLA modules carry stable names.
+def engine_warm_step(step: EngineStep, slot_s) -> EngineStep:
+    return warm_step_impl(step, slot_s, checks=False)
+
+
+def engine_apply_single(step: EngineStep, gs, mids, work_raw, valid):
+    return apply_single_impl(step, gs, mids, work_raw, valid, checks=False)
+
+
+def engine_close_step(step: EngineStep, slot_s):
+    return close_step_impl(step, slot_s, checks=False)
+
+
+warm_step = jax.jit(engine_warm_step)
+apply_single = jax.jit(engine_apply_single)
+close_step = jax.jit(engine_close_step)
 # Sanitized variants: module-level partials give sanitize.checkified a
 # stable identity to cache the checkify compile under.  user+float only:
 # apply_single's padded rows are deliberately out of range for the
@@ -251,11 +269,29 @@ class JaxStepper:
                                         errors=_ENGINE_ERRORS))
         return warm_step, apply_single, close_step
 
-    def _make_step(self) -> EngineStep:
+    def _make_step(self, *operands) -> EngineStep:
+        """The step view for one dispatch; counts its upload: the dynamic
+        columns plus the call's own ``operands`` (and the static triple
+        on the run's first dispatch)."""
+        st = self.state
         if self._static is None:
             with jax.enable_x64(True):
-                self._static = static_arrays(self.state)
-        return EngineStep.from_state(self.state, self._static)
+                self._static = static_arrays(st)
+            obs_rt.count_transfer("h2d", "engine", lambda: self._static)
+        obs_rt.count_transfer(
+            "h2d", "engine",
+            lambda: [getattr(st, name) for name in DYNAMIC_FIELDS]
+            + list(operands))
+        return EngineStep.from_state(st, self._static)
+
+    def _write_back(self, step: EngineStep, fields, *outputs) -> None:
+        """Write ``fields`` back into the host mirror; counts the
+        download of those columns and of the returned ``outputs``."""
+        step.write_back(self.state, fields=fields)
+        obs_rt.count_transfer(
+            "d2h", "engine",
+            lambda: [getattr(self.state, name) for name in fields]
+            + list(outputs))
 
     def progress_warming(self, slot_s: float) -> None:
         st = self.state
@@ -265,10 +301,10 @@ class JaxStepper:
                                str(st.n_servers))
         obs_rt.count("engine.host_sync.warm_step")
         warm_fn, _, _ = self._kernels()
+        slot = np.float64(slot_s)
         with jax.enable_x64(True):
-            step = warm_fn(self._make_step(),
-                           jnp.asarray(np.float64(slot_s)))
-            step.write_back(st, fields=("state", "warm_remaining_s"))
+            step = warm_fn(self._make_step(slot), jnp.asarray(slot))
+            self._write_back(step, ("state", "warm_remaining_s"))
 
     def apply_single_rows(self, gs: np.ndarray, mids: np.ndarray,
                           work_raw: np.ndarray):
@@ -283,21 +319,20 @@ class JaxStepper:
         obs_rt.count("engine.host_sync.apply_single")
         pad = bucket - k
         s_total = st.n_servers
-        gs_p = np.pad(gs.astype(np.int64), (0, pad),
-                      constant_values=s_total)      # OOB -> dropped
-        mids_p = np.pad(mids.astype(np.int32), (0, pad))
-        work_p = np.pad(work_raw.astype(np.float64), (0, pad))
-        valid = np.pad(np.ones(k, bool), (0, pad))
+        rows = (np.pad(gs.astype(np.int64), (0, pad),
+                       constant_values=s_total),    # OOB -> dropped
+                np.pad(mids.astype(np.int32), (0, pad)),
+                np.pad(work_raw.astype(np.float64), (0, pad)),
+                np.pad(np.ones(k, bool), (0, pad)))
         _, apply_fn, _ = self._kernels()
         with jax.enable_x64(True):
             step, sw, energy, wait, wk = apply_fn(
-                self._make_step(), jnp.asarray(gs_p),
-                jnp.asarray(mids_p), jnp.asarray(work_p),
-                jnp.asarray(valid))
-            step.write_back(st, fields=("queue_s", "current_model",
-                                        "warm_models"))
-            return (np.asarray(sw)[:k], np.asarray(energy)[:k],
-                    np.asarray(wait)[:k], np.asarray(wk)[:k])
+                self._make_step(*rows), *[jnp.asarray(a) for a in rows])
+            out = (np.asarray(sw), np.asarray(energy), np.asarray(wait),
+                   np.asarray(wk))
+            self._write_back(step, ("queue_s", "current_model",
+                                    "warm_models"), *out)
+            return tuple(a[:k] for a in out)
 
     def close_slot(self, slot_s: float):
         """Drain/bill the slot; returns the per-server power draw (J)
@@ -307,8 +342,11 @@ class JaxStepper:
                                str(st.n_servers))
         obs_rt.count("engine.host_sync.close_step")
         _, _, close_fn = self._kernels()
+        slot = np.float64(slot_s)
         with jax.enable_x64(True):
-            step, power_j, act = close_fn(
-                self._make_step(), jnp.asarray(np.float64(slot_s)))
-            step.write_back(st, fields=("queue_s", "util", "idle_slots"))
-            return np.asarray(power_j), np.asarray(act)
+            step, power_j, act = close_fn(self._make_step(slot),
+                                          jnp.asarray(slot))
+            power_j, act = np.asarray(power_j), np.asarray(act)
+            self._write_back(step, ("queue_s", "util", "idle_slots"),
+                             power_j, act)
+            return power_j, act

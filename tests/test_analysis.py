@@ -172,6 +172,26 @@ def test_lint_extra_traced_registry_hook():
     assert _rules(out) == ["host-np-call"]
 
 
+@pytest.mark.parametrize("rel, impl", [
+    ("src/repro/core/micro_jax.py", "_scan_assign_multi_impl"),
+    ("src/repro/sim/engine_jax.py", "warm_step_impl"),
+    ("src/repro/sim/engine_jax.py", "apply_single_impl"),
+    ("src/repro/sim/engine_jax.py", "close_step_impl"),
+])
+def test_lint_named_entry_bodies_are_traced(rel, impl):
+    """The production jits wrap named entry functions that call the
+    ``*_impl`` bodies; the registry keeps those bodies under the traced
+    rules, so a host call planted in one is caught."""
+    from repro.analysis import registry
+    src = (REPO / rel).read_text()
+    at = src.index(f"def {impl}(")
+    body = src.index('"""', src.index('"""', at) + 3) + 3
+    planted = src[:body] + "\n    _x = np.maximum(1, 2)" + src[body:]
+    out = hazards.lint_source(planted, rel,
+                              extra_traced=registry.EXTRA_TRACED[rel])
+    assert [f.rule for f in out if f.symbol == impl] == ["host-np-call"]
+
+
 def test_lint_tree_covers_registered_modules():
     files = hazards.jit_extent_files(REPO)
     names = {p.name for p in files}

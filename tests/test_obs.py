@@ -309,12 +309,16 @@ def test_engine_obs_off_and_report_round_trip(tmp_path):
 
 def test_obs_parity_bitwise_numpy_engine():
     """Default-on observability (and full tracing) changes NO metric:
-    the layer is observation-only."""
+    the layer is observation-only — on the numpy engine and on the fused
+    path (fused scan + jitted engine step), whose spans and transfer
+    counters sit around every dispatch."""
     topo, cs, src = _small_world(slots=6)
 
-    def summarize(obs_spec):
-        sched = TortaScheduler(5, seed=0)
+    def summarize(obs_spec, fused=False):
+        sched = TortaScheduler(5, seed=0,
+                               micro_backend="fused" if fused else None)
         return Engine(topo, cs.copy(), src, sched, seed=4,
+                      step_backend="jax" if fused else "numpy",
                       obs=obs_spec).run(6).summary()
 
     s_off = summarize(False)
@@ -322,6 +326,10 @@ def test_obs_parity_bitwise_numpy_engine():
     s_trc = summarize("trace")
     for k in METRIC_KEYS:
         assert s_off[k] == s_def[k] == s_trc[k], k
+    f_off = summarize(False, fused=True)
+    f_trc = summarize("trace", fused=True)
+    for k in METRIC_KEYS:
+        assert f_off[k] == f_trc[k], k
 
 
 def test_decision_host_sync_counter():
