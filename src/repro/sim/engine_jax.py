@@ -14,8 +14,15 @@ jitted kernels cover the interpreted engine surface:
 
 Each jits a named function, so its XLA module reads
 ``jit_engine_warm_step``, ``jit_engine_apply_single`` or
-``jit_engine_close_step`` in a profile.  ``JaxStepper`` counts the arrays
-each dispatch moves and the bytes it uploads
+``jit_engine_close_step`` in a profile.  Operands and results cross the
+host-device link packed per dtype: a dispatch uploads one float64 and one
+int32 buffer (the dynamic columns its kernel reads, then the call's own
+operands), the entry unpacks them into an ``EngineStep`` inside the jit,
+and packs what the kernel wrote into one float64 and one int32 output,
+read back together.  A transfer costs about the same whatever its size
+at fleet widths, so two buffers each way replace one array per column.
+The static hardware triple is uploaded once per run.  ``JaxStepper``
+counts the buffers each dispatch moves and the bytes it uploads
 (``device.transfers{dir=...,layer=engine}``,
 ``device.h2d_bytes{layer=engine}``).
 
@@ -32,6 +39,7 @@ trajectory parity).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 
 import jax
@@ -50,9 +58,18 @@ def _model_switch_s() -> float:
     return MODEL_SWITCH_S
 
 
-# the columns ``EngineStep.from_state`` uploads on every dispatch
-DYNAMIC_FIELDS = ("state", "warm_remaining_s", "queue_s", "util",
-                  "idle_slots", "current_model", "warm_models")
+# the dynamic columns and their dtypes in the host mirror (``ClusterState``)
+DYNAMIC_FIELDS = {"state": jnp.int8, "warm_remaining_s": jnp.float64,
+                  "queue_s": jnp.float64, "util": jnp.float64,
+                  "idle_slots": jnp.int64, "current_model": jnp.int16,
+                  "warm_models": jnp.int16}
+
+# The dynamic columns each kernel reads from and writes back to the host
+# mirror, as (float64 buffer, int32 buffer) of its packed operands/results.
+WARM_IO = (("warm_remaining_s",), ("state",))
+APPLY_IO = (("queue_s",), ("current_model", "warm_models"))
+CLOSE_READS = (("queue_s", "util"), ("state", "idle_slots"))
+CLOSE_WRITES = (("queue_s", "util"), ("idle_slots",))
 
 
 def static_arrays(st: ClusterState):
@@ -71,7 +88,9 @@ def static_arrays(st: ClusterState):
          meta_fields=[])
 @dataclasses.dataclass
 class EngineStep:
-    """Pytree view of ``ClusterState`` for the jitted slot step."""
+    """Pytree view of ``ClusterState`` for the jitted slot step, built
+    inside each jitted entry from its packed operands; a dynamic column
+    the entry's kernel does not read is ``None``."""
 
     # dynamic columns (written back after each jitted call)
     state: jax.Array             # (S,) int8
@@ -88,28 +107,6 @@ class EngineStep:
     speed: jax.Array             # (S,) float64 max(tflops/112, 0.1)
     power_w: jax.Array           # (S,) float64
     switch_scale: jax.Array      # (S,) float64
-
-    @classmethod
-    def from_state(cls, st: ClusterState,
-                   statics=None) -> "EngineStep":
-        """Build the view from a numpy ``ClusterState``.  ``statics`` is
-        an optional cached ``(speed, power_w, switch_scale)`` device
-        triple (``JaxStepper`` uploads it once per run)."""
-        if statics is None:
-            statics = static_arrays(st)
-        speed, power_w, switch_scale = statics
-        return cls(
-            **{name: jnp.asarray(getattr(st, name))
-               for name in DYNAMIC_FIELDS},
-            speed=speed, power_w=power_w, switch_scale=switch_scale)
-
-    def write_back(self, st: ClusterState,
-                   fields=DYNAMIC_FIELDS) -> None:
-        """Sync dynamic columns into the numpy ``ClusterState`` (the host
-        mirror the schedulers/oracle fallback read); callers narrow
-        ``fields`` to the columns their kernel actually wrote."""
-        for name in fields:
-            getattr(st, name)[...] = np.asarray(getattr(self, name))
 
 
 def warm_step_impl(step: EngineStep, slot_s, *,
@@ -209,18 +206,74 @@ def close_step_impl(step: EngineStep, slot_s, *, checks: bool = False):
                                idle_slots=idle), power_j, act
 
 
-# Production entries: checks=False compiles to the historical jaxprs.
-# Named functions, not partials, so the XLA modules carry stable names.
-def engine_warm_step(step: EngineStep, slot_s) -> EngineStep:
-    return warm_step_impl(step, slot_s, checks=False)
+# Packed entries: each takes the static triple and one float64 and one
+# int32 buffer that hold, in the order of its ``*_IO`` table, the columns
+# its kernel reads and then the call's own operands.  It unpacks them,
+# runs the unchanged ``*_impl`` body and packs what the kernel wrote: the
+# table's written columns, then the per-row or per-server outputs.
+def _unpack(statics, reads, floats, ints):
+    """The step over the ``reads`` columns at the front of the two
+    buffers, cast back to the mirror's dtypes (the columns not read stay
+    ``None``), and the rest of each buffer."""
+    speed, power_w, switch_scale = statics
+    step = dict.fromkeys(DYNAMIC_FIELDS)
+    rest = []
+    for names, buf in zip(reads, (floats, ints)):
+        at = 0
+        for name in names:
+            shape = speed.shape + ((WARM_SLOTS,) if name == "warm_models"
+                                   else ())
+            size = math.prod(shape)
+            step[name] = buf[at:at + size].reshape(shape).astype(
+                DYNAMIC_FIELDS[name])
+            at += size
+        rest.append(buf[at:])
+    return EngineStep(**step, speed=speed, power_w=power_w,
+                      switch_scale=switch_scale), rest
 
 
-def engine_apply_single(step: EngineStep, gs, mids, work_raw, valid):
-    return apply_single_impl(step, gs, mids, work_raw, valid, checks=False)
+def _pack(step, writes, *outputs):
+    """The ``writes`` columns of ``step`` and the float64 ``outputs`` as
+    one float64 and one int32 result buffer."""
+    floats, ints = writes
+    return (jnp.concatenate([getattr(step, name).ravel() for name in floats]
+                            + list(outputs)),
+            jnp.concatenate([getattr(step, name).ravel().astype(jnp.int32)
+                             for name in ints]))
 
 
-def engine_close_step(step: EngineStep, slot_s):
-    return close_step_impl(step, slot_s, checks=False)
+def _warm_packed(statics, floats, ints, *, checks):
+    step, (slot_s, _) = _unpack(statics, WARM_IO, floats, ints)
+    return _pack(warm_step_impl(step, slot_s[0], checks=checks), WARM_IO)
+
+
+def _apply_packed(statics, floats, ints, *, checks):
+    step, (work_raw, rows) = _unpack(statics, APPLY_IO, floats, ints)
+    gs, mids, valid = rows.reshape(3, -1)
+    step, *channels = apply_single_impl(
+        step, gs.astype(jnp.int64), mids, work_raw, valid.astype(bool),
+        checks=checks)
+    return _pack(step, APPLY_IO, *channels)
+
+
+def _close_packed(statics, floats, ints, *, checks):
+    step, (slot_s, _) = _unpack(statics, CLOSE_READS, floats, ints)
+    step, power_j, _ = close_step_impl(step, slot_s[0], checks=checks)
+    return _pack(step, CLOSE_WRITES, power_j)
+
+
+# Production entries: checks=False.  Named functions, not partials, so
+# the XLA modules carry stable names.
+def engine_warm_step(statics, floats, ints):
+    return _warm_packed(statics, floats, ints, checks=False)
+
+
+def engine_apply_single(statics, floats, ints):
+    return _apply_packed(statics, floats, ints, checks=False)
+
+
+def engine_close_step(statics, floats, ints):
+    return _close_packed(statics, floats, ints, checks=False)
 
 
 warm_step = jax.jit(engine_warm_step)
@@ -230,9 +283,9 @@ close_step = jax.jit(engine_close_step)
 # stable identity to cache the checkify compile under.  user+float only:
 # apply_single's padded rows are deliberately out of range for the
 # mode="drop" scatters, so index_checks would false-positive by design.
-_warm_step_checked = partial(warm_step_impl, checks=True)
-_apply_single_checked = partial(apply_single_impl, checks=True)
-_close_step_checked = partial(close_step_impl, checks=True)
+_warm_step_checked = partial(_warm_packed, checks=True)
+_apply_single_checked = partial(_apply_packed, checks=True)
+_close_step_checked = partial(_close_packed, checks=True)
 _ENGINE_ERRORS = "float|user"
 
 
@@ -243,12 +296,12 @@ def row_bucket(n: int) -> int:
 
 
 class JaxStepper:
-    """Host-side driver for the jitted step: owns the ``EngineStep``
-    view, pads/buckets the per-slot row channels and writes results back
-    into the numpy ``ClusterState`` mirror after each dispatch.  The
-    static hardware arrays are uploaded once and reused across every
-    dispatch of the run; only the dynamic columns each kernel touches
-    round-trip."""
+    """Host side of the jitted step: pads/buckets the per-slot
+    row channels, packs each dispatch's operands into one float64 and one
+    int32 buffer, and writes the packed results back into the numpy
+    ``ClusterState`` mirror in place.  The static hardware arrays are
+    uploaded once and reused across every dispatch of the run; only the
+    dynamic columns each kernel reads and writes cross the link."""
 
     def __init__(self, state: ClusterState):
         self.state = state
@@ -269,29 +322,36 @@ class JaxStepper:
                                         errors=_ENGINE_ERRORS))
         return warm_step, apply_single, close_step
 
-    def _make_step(self, *operands) -> EngineStep:
-        """The step view for one dispatch; counts its upload: the dynamic
-        columns plus the call's own ``operands`` (and the static triple
-        on the run's first dispatch)."""
+    def _dispatch(self, fn, reads, writes, floats=(), ints=()):
+        """One packed round trip: upload the ``reads`` columns and the
+        call's own ``floats`` / ``ints`` operands as two buffers, run
+        ``fn``, read both results back at once and write the ``writes``
+        columns into the mirror in place.  Counts the two buffers each
+        way (and the static triple on the run's first dispatch); returns
+        the float64 result past the written columns."""
         st = self.state
         if self._static is None:
             with jax.enable_x64(True):
                 self._static = static_arrays(st)
             obs_rt.count_transfer("h2d", "engine", lambda: self._static)
-        obs_rt.count_transfer(
-            "h2d", "engine",
-            lambda: [getattr(st, name) for name in DYNAMIC_FIELDS]
-            + list(operands))
-        return EngineStep.from_state(st, self._static)
-
-    def _write_back(self, step: EngineStep, fields, *outputs) -> None:
-        """Write ``fields`` back into the host mirror; counts the
-        download of those columns and of the returned ``outputs``."""
-        step.write_back(self.state, fields=fields)
-        obs_rt.count_transfer(
-            "d2h", "engine",
-            lambda: [getattr(self.state, name) for name in fields]
-            + list(outputs))
+        up = tuple(
+            np.concatenate([getattr(st, name).ravel() for name in names]
+                           + list(operands), dtype=dtype)
+            for names, operands, dtype in zip(reads, (floats, ints),
+                                              (np.float64, np.int32)))
+        obs_rt.count_transfer("h2d", "engine", lambda: up)
+        with jax.enable_x64(True):
+            down = jax.device_get(fn(self._static, *up))
+        obs_rt.count_transfer("d2h", "engine", lambda: down)
+        rest = []
+        for names, buf in zip(writes, down):
+            at = 0
+            for name in names:
+                col = getattr(st, name)
+                col[...] = buf[at:at + col.size].reshape(col.shape)
+                at += col.size
+            rest.append(buf[at:])
+        return rest[0]
 
     def progress_warming(self, slot_s: float) -> None:
         st = self.state
@@ -301,10 +361,7 @@ class JaxStepper:
                                str(st.n_servers))
         obs_rt.count("engine.host_sync.warm_step")
         warm_fn, _, _ = self._kernels()
-        slot = np.float64(slot_s)
-        with jax.enable_x64(True):
-            step = warm_fn(self._make_step(slot), jnp.asarray(slot))
-            self._write_back(step, ("state", "warm_remaining_s"))
+        self._dispatch(warm_fn, WARM_IO, WARM_IO, floats=[[slot_s]])
 
     def apply_single_rows(self, gs: np.ndarray, mids: np.ndarray,
                           work_raw: np.ndarray):
@@ -318,21 +375,15 @@ class JaxStepper:
                                f"{bucket}x{st.n_servers}")
         obs_rt.count("engine.host_sync.apply_single")
         pad = bucket - k
-        s_total = st.n_servers
-        rows = (np.pad(gs.astype(np.int64), (0, pad),
-                       constant_values=s_total),    # OOB -> dropped
-                np.pad(mids.astype(np.int32), (0, pad)),
-                np.pad(work_raw.astype(np.float64), (0, pad)),
-                np.pad(np.ones(k, bool), (0, pad)))
         _, apply_fn, _ = self._kernels()
-        with jax.enable_x64(True):
-            step, sw, energy, wait, wk = apply_fn(
-                self._make_step(*rows), *[jnp.asarray(a) for a in rows])
-            out = (np.asarray(sw), np.asarray(energy), np.asarray(wait),
-                   np.asarray(wk))
-            self._write_back(step, ("queue_s", "current_model",
-                                    "warm_models"), *out)
-            return tuple(a[:k] for a in out)
+        rows = self._dispatch(
+            apply_fn, APPLY_IO, APPLY_IO,
+            floats=[np.pad(work_raw, (0, pad))],
+            ints=[np.pad(gs, (0, pad),
+                         constant_values=st.n_servers),  # OOB -> dropped
+                  np.pad(mids, (0, pad)),
+                  np.pad(np.ones(k, np.int32), (0, pad))])
+        return tuple(rows.reshape(4, bucket)[:, :k])
 
     def close_slot(self, slot_s: float):
         """Drain/bill the slot; returns the per-server power draw (J)
@@ -342,11 +393,6 @@ class JaxStepper:
                                str(st.n_servers))
         obs_rt.count("engine.host_sync.close_step")
         _, _, close_fn = self._kernels()
-        slot = np.float64(slot_s)
-        with jax.enable_x64(True):
-            step, power_j, act = close_fn(self._make_step(slot),
-                                          jnp.asarray(slot))
-            power_j, act = np.asarray(power_j), np.asarray(act)
-            self._write_back(step, ("queue_s", "util", "idle_slots"),
-                             power_j, act)
-            return power_j, act
+        power_j = self._dispatch(close_fn, CLOSE_READS, CLOSE_WRITES,
+                                 floats=[[slot_s]])
+        return power_j, st.active_mask()

@@ -4,6 +4,8 @@ scan, device-array ``BatchDecision`` round-trips, and the satellite
 regressions (``make_dataset`` vectorization, ``prev_nu`` staleness,
 arrivals-history buffering)."""
 
+import jax
+import jax.numpy as jnp
 import networkx as nx
 import numpy as np
 import pytest
@@ -20,9 +22,11 @@ from repro.core.predictor import K_HIST, make_dataset
 from repro.core.torta import TortaScheduler
 from repro.sim import (Engine, make_cluster_state, make_topology,
                        make_workload)
-from repro.sim.cluster import throughput_per_slot
+from repro.sim.cluster import SWITCH_POWER_FRAC, throughput_per_slot
 from repro.sim.engine import FailureEvent, SlotObs
-from repro.sim.state import ACTIVE, MODEL_NAMES, OFF
+from repro.sim.engine_jax import (DYNAMIC_FIELDS, EngineStep, JaxStepper,
+                                  close_step_impl, static_arrays)
+from repro.sim.state import ACTIVE, MODEL_NAMES, OFF, WARMING
 from repro.sim.topology import Topology
 from repro.workload import make_source
 
@@ -124,6 +128,101 @@ def test_fused_slot_end_to_end_exact():
                   step_backend="jax").run(8).summary()
     for k in METRIC_KEYS:
         assert s_np[k] == s_fu[k], k
+
+
+def _numpy_warm(cs, slot_s):
+    """``Engine._progress_warming``'s numpy block."""
+    warming = cs.state == WARMING
+    cs.warm_remaining_s[warming] -= slot_s
+    done = warming & (cs.warm_remaining_s <= 0)
+    cs.state[done] = ACTIVE
+    cs.warm_remaining_s[done] = 0.0
+
+
+def _numpy_single(cs, gs, mids, work_raw):
+    """The numpy engine's grouped apply for single-task servers."""
+    speed = np.maximum(cs.tflops[gs] / 112.0, 0.1)
+    sw = cs.switch_cost_rows(gs, mids)
+    energy = np.where(sw > 0, sw * cs.power_w[gs] * SWITCH_POWER_FRAC, 0.0)
+    cs.note_model_rows(gs, mids)
+    wk = work_raw / speed
+    wait = cs.queue_s[gs] + sw
+    cs.queue_s[gs] += sw + wk
+    return sw, energy, wait, wk
+
+
+def _numpy_close(cs, slot_s):
+    """``Engine._finish_slot``'s numpy drain and power block."""
+    act = cs.active_mask()
+    busy = np.minimum(cs.queue_s, slot_s)
+    cs.util = np.where(act, busy / slot_s, cs.util)
+    cs.idle_slots = np.where(
+        act, np.where(cs.util > 0.05, 0, cs.idle_slots + 1), cs.idle_slots)
+    cs.queue_s = np.where(act, np.maximum(0.0, cs.queue_s - slot_s),
+                          cs.queue_s)
+    return np.where(act, (0.1 + 0.9 * cs.util) * cs.power_w * slot_s,
+                    0.0), act
+
+
+def _unpacked_close_power(cs, slot_s):
+    """The close kernel's power draw, run on the mirror's columns as
+    separate arrays.  Where the utilization is fractional it differs from
+    the numpy block's by a few ulps: XLA's CPU backend contracts
+    ``0.1 + 0.9 * util`` into one fused multiply-add."""
+    with jax.enable_x64(True):
+        step = EngineStep(
+            **{name: jnp.asarray(getattr(cs, name))
+               for name in DYNAMIC_FIELDS},
+            **dict(zip(("speed", "power_w", "switch_scale"),
+                       static_arrays(cs))))
+        _, power, _ = jax.jit(close_step_impl)(step, slot_s)
+        return np.asarray(power)
+
+
+def _assert_mirrors_equal(a, b):
+    for name in DYNAMIC_FIELDS:
+        col_a, col_b = getattr(a, name), getattr(b, name)
+        assert col_a.dtype == col_b.dtype, name
+        np.testing.assert_array_equal(col_a, col_b, err_msg=name)
+
+
+@pytest.mark.parametrize("n_rows", [11, 50, 200])
+def test_packed_stepper_matches_numpy_blocks(n_rows):
+    """Warming, the single-task apply and the close through the packed
+    buffers leave the ``ClusterState`` mirror and return outputs bitwise
+    equal to the numpy engine's blocks, with padded row buckets (16, 64,
+    256) and warming servers; int8 states, int16 models and int64 idle
+    counts round-trip through the int32 buffer.  The close's power draw
+    is held bitwise to the kernel's on unpacked columns
+    (``_unpacked_close_power`` says why not to numpy's)."""
+    cs, rng = _world(5, 60, seed=n_rows)
+    s = cs.n_servers
+    warming = rng.random(s) < 0.2
+    cs.state[warming] = WARMING
+    cs.warm_remaining_s[:] = np.where(warming, rng.uniform(1, 90, s), 0.0)
+    cs.idle_slots[:] = rng.integers(0, 40, s)
+    ref = cs.copy()
+    stepper = JaxStepper(cs)
+    gs = rng.choice(s, n_rows, replace=False).astype(np.int64)
+    mids = rng.integers(0, N_MODELS, n_rows).astype(np.int64)
+    work = rng.exponential(20.0, n_rows)
+    for slot_s in (45.0, 30.0):
+        stepper.progress_warming(slot_s)
+        _numpy_warm(ref, slot_s)
+        _assert_mirrors_equal(cs, ref)
+        got = stepper.apply_single_rows(gs, mids, work)
+        want = _numpy_single(ref, gs, mids, work)
+        for g, w in zip(got, want):
+            assert g.shape == (n_rows,)
+            np.testing.assert_array_equal(g, w)
+        _assert_mirrors_equal(cs, ref)
+        unpacked = _unpacked_close_power(ref, slot_s)
+        power, act = stepper.close_slot(slot_s)
+        _, want_act = _numpy_close(ref, slot_s)
+        np.testing.assert_array_equal(act, want_act)
+        np.testing.assert_array_equal(power, unpacked)
+        _assert_mirrors_equal(cs, ref)
+    assert (cs.state == WARMING).any() and (cs.warm_models != -1).any()
 
 
 def test_step_backend_rejects_unknown():

@@ -1,8 +1,8 @@
 """Span and counter coverage of the fused slot step: every phase of an
 engine slot runs inside a span, each under its parent and opened once per
 slot; the apply paths' row counts add up to the assigned rows; the fused
-scan's upload is counted in transfers and bytes; and compiles carry the
-jitted programs' names."""
+scan's upload and the engine step's packed buffers are counted in
+transfers and bytes; and compiles carry the jitted programs' names."""
 import dataclasses
 import inspect
 
@@ -14,7 +14,7 @@ from repro.core import micro_jax
 from repro.core.torta import TortaScheduler
 from repro.obs import Tracer
 from repro.obs import runtime as obs_rt
-from repro.sim import Engine, make_cluster_state
+from repro.sim import Engine, engine_jax, make_cluster_state
 from repro.sim.cluster import throughput_per_slot
 from repro.sim.engine_jax import JaxStepper
 from repro.workload import make_source
@@ -194,6 +194,43 @@ def test_micro_upload_bytes_are_the_scan_operands(monkeypatch):
         assert c.get("device.h2d_bytes", layer=layer) > 0
         assert c.get("device.transfers", dir="h2d", layer=layer) > 0
         assert c.get("device.transfers", dir="d2h", layer=layer) > 0
+
+
+def test_engine_dispatches_move_two_packed_buffers_each_way(monkeypatch):
+    """Per engine dispatch, ``device.transfers{layer=engine}`` grows by 2
+    up and 2 down (one float64 and one int32 buffer each way) and
+    ``device.h2d_bytes{layer=engine}`` by the packed buffers' ``nbytes``;
+    the static triple goes up once, before the run's first dispatch."""
+    seen = []
+
+    def spy_on(name):
+        kernel = getattr(engine_jax, name)
+
+        def spy(statics, floats, ints):
+            c = obs_rt.active().counters
+            seen.append((name, floats.nbytes + ints.nbytes,
+                         sum(a.nbytes for a in statics),
+                         c.get("device.transfers", dir="h2d", layer="engine"),
+                         c.get("device.transfers", dir="d2h", layer="engine"),
+                         c.get("device.h2d_bytes", layer="engine")))
+            return kernel(statics, floats, ints)
+
+        monkeypatch.setattr(engine_jax, name, spy)
+
+    for name in ("warm_step", "apply_single", "close_step"):
+        spy_on(name)
+    eng = _engine(None)
+    eng.run(SLOTS)
+    names = [s[0] for s in seen]
+    assert names.count("close_step") == SLOTS and "apply_single" in names
+    # the uploads are counted before the dispatch, the read-back after it
+    n = np.arange(1, len(seen) + 1)
+    assert [s[3] for s in seen] == (3 + 2 * n).tolist()
+    assert [s[4] for s in seen] == (2 * (n - 1)).tolist()
+    assert [s[5] for s in seen] == (
+        seen[0][2] + np.cumsum([s[1] for s in seen])).tolist()
+    c = eng.obs.counters
+    assert c.get("device.transfers", dir="d2h", layer="engine") == 2 * n[-1]
 
 
 def test_cold_run_counts_compiles_by_program_name():

@@ -80,38 +80,47 @@ def test_fused_scan_compiles_15x200(one_chip):
 
 @pytest.fixture(scope="module")
 def engine_step_spec(one_chip):
-    """The jitted engine step's operand shapes at a 25x500 fleet."""
+    """The jitted engine step's operands at a 25x500 fleet: the static
+    triple, and a maker of its packed float64 / int32 buffer shapes (the
+    columns a kernel reads plus the call's own operands)."""
     from repro.sim import make_cluster_state
-    from repro.sim.engine_jax import EngineStep
 
     st = make_cluster_state(25, seed=3, servers_per_region=(500, 501))
     assert st.n_servers == 12500
     with jax.enable_x64(True):
-        step = EngineStep.from_state(st)
-        return jax.tree.map(
-            lambda a: _spec(one_chip, a.shape, a.dtype), step)
+        statics = tuple(_spec(one_chip, (st.n_servers,), jnp.float64)
+                        for _ in range(3))
+
+    def packed(reads, n_floats, n_ints):
+        n_f, n_i = (sum(getattr(st, name).size for name in names)
+                    for names in reads)
+        with jax.enable_x64(True):
+            return (_spec(one_chip, (n_f + n_floats,), jnp.float64),
+                    _spec(one_chip, (n_i + n_ints,), jnp.int32))
+
+    return statics, packed
 
 
 def test_engine_warm_and_close_steps_compile_12500(one_chip,
                                                    engine_step_spec):
-    from repro.sim.engine_jax import close_step, warm_step
+    from repro.sim.engine_jax import (CLOSE_READS, WARM_IO, close_step,
+                                      warm_step)
 
-    with jax.enable_x64(True):
-        slot_s = _spec(one_chip, (), jnp.float64)
-        _fits(warm_step.lower(engine_step_spec, slot_s).compile())
-        _fits(close_step.lower(engine_step_spec, slot_s).compile())
+    statics, packed = engine_step_spec
+    with jax.enable_x64(True):                    # + the slot length
+        _fits(warm_step.lower(statics, *packed(WARM_IO, 1, 0)).compile())
+        _fits(close_step.lower(statics,
+                               *packed(CLOSE_READS, 1, 0)).compile())
 
 
 def test_engine_apply_single_compiles_12500(one_chip, engine_step_spec):
-    from repro.sim.engine_jax import apply_single, row_bucket
+    from repro.sim.engine_jax import APPLY_IO, apply_single, row_bucket
 
-    rows = row_bucket(20000)
+    statics, packed = engine_step_spec
+    rows = row_bucket(20000)          # work_raw; server ids, models, valid
     with jax.enable_x64(True):
-        _fits(apply_single.lower(
-            engine_step_spec, _spec(one_chip, (rows,), jnp.int64),
-            _spec(one_chip, (rows,), jnp.int32),
-            _spec(one_chip, (rows,), jnp.float64),
-            _spec(one_chip, (rows,), jnp.bool_)).compile())
+        _fits(apply_single.lower(statics,
+                                 *packed(APPLY_IO, rows, 3 * rows)).compile())
 
 
 def _kernel_compiled(compiled):
