@@ -1,7 +1,8 @@
 """The output check: the program's answers against the plain reference.
 
 After the window has closed, ``replay`` walks every slot the run made,
-warm-up and window alike, through ``harness.reference`` in check mode:
+warm-up and window alike, through the plain reference in check mode
+(``harness.reference``, or the copy the configuration names):
 for each slot the reference computes each layer's answer from the state
 the program's earlier answers left, compares it with the program's, and
 then applies the program's answer.  Numbers compared, each against its
@@ -39,7 +40,8 @@ from typing import Dict, List
 import numpy as np
 
 from harness import world
-from harness.reference import Precision, Reference
+from harness.manifest import reference_class
+from harness.reference import Precision
 
 # name: limit.  The reasons and the readings they were set from are in
 # PERF.md ("Output check"); exact comparisons have the limit 0.
@@ -112,10 +114,12 @@ def per_slot_responses(metrics, n_slots: int) -> List[np.ndarray]:
 
 
 def replay(cfg: dict, fleet: world.Fleet, latency: np.ndarray, run,
-           seed: int, log=print,
-           precision: Precision = Precision()) -> Readings:
-    """Replay the run through the reference and read every number."""
-    ref = Reference(cfg, fleet, latency, precision)
+           seed: int, log=print, precision: Precision = Precision(),
+           root=None) -> Readings:
+    """Replay the run through the reference the configuration names (see
+    ``manifest.reference_class``; ``root`` is the checkout's) and read
+    every number."""
+    ref = reference_class(cfg, root)(cfg, fleet, latency, precision)
     end = run.end_slot
     calls = {c.t: c for c in run.calls}
     sizes = {t: len(run.slots[t]) for t in range(run.s0, end)}

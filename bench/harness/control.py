@@ -1,4 +1,5 @@
-"""The control: the plain reference put in the program's place, computed
+"""The control: the plain reference (the one the configuration names, see
+``manifest.reference_class``) put in the program's place, computed
 one step below the configuration's stated precision (float32 for its
 float64 layers, bfloat16 for its float32 Sinkhorn and locality dots).
 
@@ -16,7 +17,8 @@ from typing import Dict, List
 import numpy as np
 
 from harness import check, program, world
-from harness.reference import Precision, Reference
+from harness.manifest import reference_class
+from harness.reference import Precision
 
 
 @dataclasses.dataclass
@@ -34,9 +36,10 @@ def window_slots(traffic: world.Traffic) -> int:
 
 
 def run_control(cfg: dict, traffic: world.Traffic, fleet: world.Fleet,
-                latency, precision: Precision = None) -> program.ProgramRun:
+                latency, precision: Precision = None,
+                root=None) -> program.ProgramRun:
     precision = precision or Precision.below_stated()
-    ref = Reference(cfg, fleet, latency, precision)
+    ref = reference_class(cfg, root)(cfg, fleet, latency, precision)
     s0 = int(cfg["warmup_slots"])
     end = s0 + window_slots(traffic)
     slots = [traffic.slot(t) for t in range(end + 1)]

@@ -3,14 +3,17 @@
 A cell names a configuration and a traffic mix; each lives in a file of
 its own (``bench/configs/<name>.json``, ``bench/traffic/<name>.json``), and
 each per-layer metric is a reader in ``bench/metrics/<name>.py`` with a
-``read(ctx)`` function.  Adding a cell, a mix or a metric is adding such
-files and entries; nothing here changes.
+``read(ctx)`` function; a configuration may name its own copy of the
+plain reference (``"reference": "bench/<path>.py"``).  Adding a cell, a
+mix, a metric or a reference is adding such files and entries; nothing
+here changes.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 import pathlib
+import sys
 from typing import Callable, List, Optional
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
@@ -47,13 +50,38 @@ class Manifest:
                 if cell in m.get("workloads", [cell])]
 
     def reader(self, metric: str) -> Callable:
-        path = self.bench / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{metric.replace('.', '_').replace('-', '_')}",
-            path)
-        module = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(module)
-        return module.read
+        return _load(self.bench / "metrics" / f"{metric}.py",
+                     f"bench_metric_{metric}").read
+
+
+def _load(path: pathlib.Path, name: str):
+    """The Python file ``path`` as a module named ``name``."""
+    name = name.replace(".", "_").replace("-", "_").replace("/", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    # dataclasses look their module up while the file runs
+    sys.modules[name] = module
+    spec.loader.exec_module(module)
+    return module
+
+
+def reference_class(cfg: dict, root: Optional[pathlib.Path] = None):
+    """The ``Reference`` class of the plain reference the configuration
+    names under ``reference`` (a ``.py`` file under the checkout's
+    ``bench/``, given from the root of the checkout ``root``, by default
+    the one that holds this file), or ``harness.reference``'s."""
+    name = cfg.get("reference")
+    if name is None:
+        from harness.reference import Reference
+        return Reference
+    root = pathlib.Path(root) if root is not None else BENCH_DIR.parent
+    path = (root / name).resolve()
+    if (path.suffix != ".py"
+            or (root / BENCH_DIR.name).resolve() not in path.parents):
+        raise ValueError(f"reference {name!r} of configuration "
+                         f"{cfg.get('name')!r}: not a .py file under "
+                         f"{BENCH_DIR.name}/")
+    return _load(path, f"bench_reference_{name[:-3]}").Reference
 
 
 def span_total(ctx, name: str) -> Optional[float]:

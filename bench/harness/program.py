@@ -3,7 +3,8 @@
 The window runs the engine's slot loop, ``Engine.run`` -> ``_run_loop``,
 with ``TortaScheduler(R, micro_backend="fused")`` and
 ``Engine(..., step_backend="jax")``.  The program receives only the
-fleet, topology and traffic that ``harness.world`` generates; the
+fleet, topology and traffic that ``harness.world`` generates, and the
+configuration's numbers as ``harness.handover`` hands them over; the
 benchmark wraps two of its seams:
 
 * the demand source (``SlotSource``): it stamps the host clock at each
@@ -29,7 +30,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from harness import world
+from harness import handover, world
 
 
 class WindowClosed(Exception):
@@ -44,16 +45,13 @@ class OutOfTraffic(RuntimeError):
 
 
 def program_world(cfg: dict, fleet: world.Fleet, latency, graph):
-    """The benchmark's fleet and topology as the program's types."""
-    from repro.sim.state import ClusterState, GPU_NAMES, KINDS, MODEL_NAMES
+    """The benchmark's fleet and topology as the program's types.  Raises
+    ``handover.Refused`` where the configuration states what the program
+    would not run (see ``harness.handover``)."""
+    from repro.sim.state import ClusterState
     from repro.sim.topology import Topology
 
-    names, _ = world.gpu_table(cfg)
-    models, _ = world.model_table(cfg)
-    if (tuple(names) != GPU_NAMES or tuple(models) != MODEL_NAMES
-            or tuple(cfg["kinds"]) != KINDS):
-        raise ValueError("the configuration's GPU, model or kind order "
-                         "differs from the program's catalog")
+    handover.refuse(cfg)
     state = ClusterState(
         **{f.name: getattr(fleet, f.name).copy()
            for f in dataclasses.fields(fleet)},

@@ -18,7 +18,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from harness import check, program, world
+from harness import check, handover, program, world
 from harness.manifest import Manifest
 from harness.meters import CompileMeter
 
@@ -103,10 +103,14 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
         marker["compiles"] = meter.compiles
 
     with CompileMeter() as meter:
-        run = program.run_program(
-            cfg, traffic, fleet, latency, graph, seconds=window_s,
-            obs_spec="trace-xla" if traced else None, on_open_extra=on_open,
-            log=log)
+        try:
+            run = program.run_program(
+                cfg, traffic, fleet, latency, graph, seconds=window_s,
+                obs_spec="trace-xla" if traced else None,
+                on_open_extra=on_open, log=log)
+        except handover.Refused as err:
+            print(f"bench: {err}", file=sys.stderr, flush=True)
+            return 5
         if traced:
             marker["window"].__exit__(None, None, None)
             jax.profiler.stop_trace()
@@ -173,7 +177,8 @@ def run_cell(root: pathlib.Path, workload: str, seed: int, seconds: float,
     log(f"counters in the window: {json.dumps(run.window_counters)}")
 
     t_check = time.perf_counter()
-    readings = check.replay(cfg, fleet, latency, run, seed, log=log)
+    readings = check.replay(cfg, fleet, latency, run, seed, log=log,
+                            root=root)
     log(f"output check: {readings.checked_tasks} tasks scored on slots "
         f"{readings.checked_slots}, {time.perf_counter() - t_check:.1f} s")
 
